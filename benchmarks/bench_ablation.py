@@ -191,7 +191,8 @@ def main(argv=None):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.resharding import reshard
-        mesh = jax.make_mesh(({pipe}, {tp}), ("pipe", "tp"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh(({pipe}, {tp}), ("pipe", "tp"))
         x = jax.random.normal(jax.random.PRNGKey(0),
                               ({pipe}, {rows}, {feat}))
         x = jax.device_put(x, NamedSharding(mesh, P("pipe", None, "tp")))
@@ -206,33 +207,34 @@ def main(argv=None):
                 elems *= int(d)
             print(f"BYTES {{strat}} {{elems * 4}}")
     """)
-    env = dict(os.environ)
+    # a CPU lowering by design: the child must never reach for the chip,
+    # which this (JAX-initialized) parent may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src") + ":" + \
         env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=300, env=env)
     if r.returncode != 0:
-        emit("table_resharding.measured_bytes", "n/a",
-             f"virtual-device lowering failed: {r.stderr[-200:]}")
-    else:
-        measured = dict(
-            (m.group(1), int(m.group(2)))
-            for m in re.finditer(r"BYTES (\w+) (\d+)", r.stdout))
-        # per-rank payloads: naive sends the FULL per-stage activation
-        # from every source rank; sr_ag sends each rank's 1/tp shard
-        # (one activation copy total, = the closed form's cross_bytes)
-        act_f32 = rows * feat * 4            # one stage's activation
-        analytic = {"naive": naive_cost(act_f32, tp, tp).cross_bytes,
-                    "sr_ag": sr_ag_cost(act_f32, tp, tp).cross_bytes // tp}
-        for strat in ("naive", "sr_ag"):
-            ok = measured[strat] == analytic[strat]
-            emit(f"table_resharding.measured_bytes.{strat}",
-                 f"{measured[strat]}B",
-                 f"per-rank cross-stage payload from StableHLO vs "
-                 f"analytic {analytic[strat]}B — "
-                 f"{'MATCH' if ok else 'MISMATCH'} "
-                 f"(pipe={pipe} tp={tp} act={act_f32}B f32)")
+        raise RuntimeError(f"virtual-device reshard lowering failed:\n"
+                           f"{r.stderr[-2000:]}")
+    measured = dict(
+        (m.group(1), int(m.group(2)))
+        for m in re.finditer(r"BYTES (\w+) (\d+)", r.stdout))
+    # per-rank payloads: naive sends the FULL per-stage activation
+    # from every source rank; sr_ag sends each rank's 1/tp shard
+    # (one activation copy total, = the closed form's cross_bytes)
+    act_f32 = rows * feat * 4            # one stage's activation
+    analytic = {"naive": naive_cost(act_f32, tp, tp).cross_bytes,
+                "sr_ag": sr_ag_cost(act_f32, tp, tp).cross_bytes // tp}
+    for strat in ("naive", "sr_ag"):
+        ok = measured[strat] == analytic[strat]
+        emit(f"table_resharding.measured_bytes.{strat}",
+             f"{measured[strat]}B",
+             f"per-rank cross-stage payload from StableHLO vs "
+             f"analytic {analytic[strat]}B — "
+             f"{'MATCH' if ok else 'MISMATCH'} "
+             f"(pipe={pipe} tp={tp} act={act_f32}B f32)")
 
     # dp ablation (DESIGN.md §9).  (a) Gradient-sync mode: per-bucket
     # byte accounting of the pacing stage's gradient volume under the

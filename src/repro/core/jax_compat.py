@@ -1,11 +1,8 @@
-"""Version-compat shims for JAX APIs the runtime depends on.
+"""One ``shard_map`` signature for the runtime.
 
-The codebase targets the modern ``jax.shard_map`` spelling
-(``check_vma`` / ``axis_names``); the pinned CPU test image ships an
-older jaxlib where only ``jax.experimental.shard_map.shard_map`` exists
-and takes ``check_rep`` / ``auto`` instead.  :func:`shard_map` presents
-one signature over both: ``manual_axes`` names the axes the body handles
-with explicit collectives, every other mesh axis stays GSPMD-automatic.
+``manual_axes`` names the axes the body handles with explicit
+collectives; every other mesh axis stays GSPMD-automatic.  Replication
+checking (``check_vma``) is off.
 """
 from __future__ import annotations
 
@@ -16,18 +13,8 @@ import jax
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               manual_axes: Optional[Set[str]] = None):
-    axes = set(mesh.axis_names)
-    manual = set(manual_axes) if manual_axes is not None else axes
-    auto = axes - manual
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": False}
-        if auto:
-            kwargs["axis_names"] = manual
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kwargs = {"check_rep": False}
-    if auto:
-        kwargs["auto"] = frozenset(auto)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+    kwargs = {"check_vma": False}
+    if manual_axes is not None and set(manual_axes) != set(mesh.axis_names):
+        kwargs["axis_names"] = set(manual_axes)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -124,5 +124,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, hd), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

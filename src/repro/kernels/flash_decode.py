@@ -17,9 +17,10 @@ sweep — the same carry structure as ``flash_attention``.
 
 Masking (causal bound at ``pos``, sliding window, ring-buffer slot→
 position mapping, sequence padding) arrives as a precomputed additive
-bias row per batch element: position logic stays in cheap O(S) jnp in
-the wrapper (``ops.flash_decode``), the kernel body only adds a (1,
-PAGE) slice — which also means per-sequence lengths (a paged cache with
+bias row per batch element, laid out (B, 1, S) so that its (1, PAGE)
+block is legal on the chip for any batch: position logic stays in cheap
+O(S) jnp in the wrapper (``ops.flash_decode``), the kernel body only
+adds a (1, PAGE) slice — which also means per-sequence lengths (a paged cache with
 ragged batches) need no kernel change, just a per-row bias.  Pages that
 are fully masked (outside the window, or padding) are skipped via a
 ``pl.when`` guard on the page's bias maximum.
@@ -57,7 +58,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bias = bias_ref[...]                                   # (1, PAGE)
+    bias = bias_ref[0]                                     # (1, PAGE)
     # a page whose every slot is masked contributes nothing — skip it
     live = jnp.max(bias) > 0.5 * NEG_INF
 
@@ -94,14 +95,14 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  page_size: int = DEFAULT_PAGE,
                  interpret: bool = True) -> jax.Array:
     """q: (B, KV, G, hd) — one query token, heads grouped per kv head;
-    k/v: (B, KV, S, hd) cache layout; bias: (B, S) additive fp32 mask
+    k/v: (B, KV, S, hd) cache layout; bias: (B, 1, S) additive fp32 mask
     (0 for attendable slots, NEG_INF for masked/padded).  S must be a
     multiple of ``page_size`` (the wrapper pads).  Returns
     (B, KV, G, hd)."""
     B, KV, G, hd = q.shape
     S = k.shape[2]
     assert S % page_size == 0, (S, page_size)
-    assert bias.shape == (B, S), (bias.shape, B, S)
+    assert bias.shape == (B, 1, S), (bias.shape, B, S)
     num_pages = S // page_size
 
     qr = q.reshape(B * KV, G, hd)
@@ -120,7 +121,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, page_size, hd), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, page_size, hd), lambda b, j: (b, j, 0)),
             # bias is per BATCH row, shared by that row's kv heads
-            pl.BlockSpec((1, page_size), lambda b, j: (b // KV, j)),
+            pl.BlockSpec((1, 1, page_size), lambda b, j: (b // KV, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, G, hd), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), q.dtype),
@@ -130,5 +131,6 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, hd), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_decode",
     )(qr, kr, vr, bias.astype(jnp.float32))
     return out.reshape(B, KV, G, hd)

@@ -132,13 +132,13 @@ def flash_decode(q, k, v, pos, *, window=0, softcap=0.0, ring=False,
     valid = (k_pos >= 0) & (k_pos <= pos)
     if window:
         valid = valid & (k_pos > pos - window)
-    bias = jnp.where(valid, 0.0, NEG_INF)[None, :]         # (1, S)
-    bias = jnp.broadcast_to(bias, (B, S))
+    bias = jnp.where(valid, 0.0, NEG_INF)[None, None, :]   # (1, 1, S)
+    bias = jnp.broadcast_to(bias, (B, 1, S))
     spad = (-S) % page_size
     if spad:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, spad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, spad), (0, 0)))
-        bias = jnp.pad(bias, ((0, 0), (0, spad)),
+        bias = jnp.pad(bias, ((0, 0), (0, 0), (0, spad)),
                        constant_values=NEG_INF)
     out = _fd.flash_decode(qg, k, v, bias, softcap=softcap,
                            page_size=page_size, interpret=not _is_tpu())

@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_ROWS = 256
+BLOCK_ROWS = 256     # a multiple of SUBLANE
+SUBLANE = 8
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -30,18 +31,24 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
     for s in shape[:-1]:
         rows *= s
     xr = x.reshape(rows, d)
-    br = min(block_rows, rows)
-    while rows % br:
-        br -= 1
+    # the row block must be a multiple of the 8-row sublane tile: pad the
+    # rows up to one (zero rows normalise to zero and are sliced off)
+    padded = -(-rows // SUBLANE) * SUBLANE
+    br = max(SUBLANE, min(block_rows, padded) // SUBLANE * SUBLANE)
+    while padded % br:
+        br -= SUBLANE
+    if padded != rows:
+        xr = jnp.pad(xr, ((0, padded - rows), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
-        grid=(rows // br,),
+        grid=(padded // br,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((padded, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xr, scale)
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)

@@ -33,20 +33,28 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0].astype(jnp.float32)           # (c, p)
-    dt = dt_ref[0].astype(jnp.float32)         # (1, c) row
-    A = a_ref[0, 0]                            # scalar decay rate (<0)
+    dt_row = dt_ref[0].astype(jnp.float32)     # (1, c)
+    A = a_ref[0]                               # (1, 1) decay rate (<0)
     Bm = b_ref[0].astype(jnp.float32)          # (c, n)
     Cm = c_ref[0].astype(jnp.float32)          # (c, n)
 
-    a = A * dt[0]                              # (c,)
-    cum = jnp.cumsum(a)                        # (c,)
-    xd = x * dt[0][:, None]                    # (c, p)
-
-    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j <= i
-    diff = cum[:, None] - cum[None, :]
+    # Mosaic lowers neither cumsum nor a lane->sublane reshape, so the
+    # in-chunk prefix sums and the column form of dt come from masked
+    # reductions over the (c, c) triangle (exact fp32 adds)
     i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(i >= j, jnp.exp(diff), 0.0)
+    dt_col = jnp.sum(jnp.where(i == j, dt_row, 0.0), axis=1,
+                     keepdims=True)            # (c, 1)
+    a_row = A * dt_row                         # (1, c)
+    a_col = A * dt_col                         # (c, 1)
+    cum_col = jnp.sum(jnp.where(j <= i, a_row, 0.0), axis=1,
+                      keepdims=True)           # (c, 1): Σ_{k<=i} a_k
+    cum_row = jnp.sum(jnp.where(i <= j, a_col, 0.0), axis=0,
+                      keepdims=True)           # (1, c): Σ_{k<=j} a_k
+    xd = x * dt_col                            # (c, p)
+
+    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j <= i
+    L = jnp.where(i >= j, jnp.exp(cum_col - cum_row), 0.0)
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * L
     y_intra = jax.lax.dot_general(scores, xd, (((1,), (0,)), ((), ())),
@@ -54,15 +62,15 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref,
 
     # carried-state contribution: y_off = (C * exp(cum)) @ state^T
     state = state_ref[...]                     # (p, n)
-    c_dec = Cm * jnp.exp(cum)[:, None]
+    c_dec = Cm * jnp.exp(cum_col)
     y_off = jax.lax.dot_general(c_dec, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
     y_ref[0] = (y_intra + y_off).astype(y_ref.dtype)
 
     # state update: state' = state * exp(sum a) + xd^T @ (B * exp(cum_last - cum))
-    total = cum[chunk - 1]
-    b_dec = Bm * jnp.exp(total - cum)[:, None]
+    total = jnp.sum(a_row, axis=1, keepdims=True)          # (1, 1)
+    b_dec = Bm * jnp.exp(total - cum_col)
     upd = jax.lax.dot_general(xd, b_dec, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (p, n)
     state_ref[...] = state * jnp.exp(total) + upd
@@ -112,6 +120,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xr, dtr, Ar, Br, Cr)
     y = y.reshape(b, h, S, p).transpose(0, 2, 1, 3)
     fin = fin.reshape(b, h, p, n)

@@ -11,13 +11,20 @@ Multi-pod  : (pod=2, data=16, model=16)     = 512 chips; the ``pod`` axis is
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the sharding rules
+    (``sharding.ctx.constrain``) emit ``with_sharding_constraint``, which
+    only accepts Auto axes; ``jax.make_mesh`` defaults to Explicit."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1, data: int = 0, pod: int = 0) -> Mesh:
@@ -25,9 +32,9 @@ def make_local_mesh(model: int = 1, data: int = 0, pod: int = 0) -> Mesh:
     n = len(jax.devices())
     if pod:
         data = data or (n // (model * pod))
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+        return auto_mesh((pod, data, model), ("pod", "data", "model"))
     data = data or (n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used for the roofline (assignment-provided)

@@ -14,8 +14,10 @@ in ``<run-dir>/metrics.jsonl`` (``repro.obs.metrics`` — DESIGN.md §14).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,11 +29,22 @@ from ..models import model as M
 # metrics registry with the observability subsystem (DESIGN.md §14)
 from ..obs.metrics import percentile  # noqa: F401
 from ..training import serve_step as SS
+from .compile_cache import enable_compile_cache
 
 BACKENDS = ["auto", "einsum", "pallas"]
 
 
-def main():
+@dataclasses.dataclass
+class ServeRun:
+    """What a serving run hands back to an in-process caller."""
+    tokens: Any                  # (batch, gen) generated token ids
+    prefill_s: float             # first call: includes its compile
+    decode_compile_s: float
+    decode_latency_s: dict       # per-step histogram summary (p50, p95, ...)
+    compiled_decode: Any         # its HLO shows which kernels decode runs
+
+
+def main(argv: Optional[List[str]] = None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_configs())
     ap.add_argument("--batch", type=int, default=4)
@@ -49,8 +62,9 @@ def main():
     ap.add_argument("--log-every", type=int, default=0,
                     help="also emit an interim decode histogram row "
                          "every N decode steps (0 = final row only)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     name = canonical(args.arch)
     cfg = get_smoke_config(name) if args.smoke else get_config(name)
     total = args.prompt_len + args.gen
@@ -87,9 +101,13 @@ def main():
 
     tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
     out = [tok]
-    # warm the decode jit outside the timed loop so step times are
-    # steady-state, then time every step individually: the mean hides
-    # exactly the tail the kernel work targets
+    # compile and warm the decode step outside the timed loop so step
+    # times are steady-state, then time every step individually: the
+    # mean hides exactly the tail the kernel work targets
+    t_c = time.perf_counter()
+    decode = decode.lower(params, cache, tok, jnp.int32(plen)).compile()
+    t_compile = time.perf_counter() - t_c
+    print(f"decode compile: {t_compile:.1f}s")
     _ = jax.block_until_ready(decode(params, cache, tok, jnp.int32(plen)))
     hist = reg.histogram("decode_latency_s")
     pos = plen
@@ -103,8 +121,8 @@ def main():
         if args.log_every and (i + 1) % args.log_every == 0:
             metrics.log_histogram("decode_latency_s", hist)
     gen = jnp.concatenate(out, axis=1)
+    s = hist.summary()
     if hist.count:
-        s = hist.summary()
         p50, p95, tot = s["p50"], s["p95"], s["mean"] * s["count"]
         reg.gauge("decode_tok_per_s").set(
             args.batch * hist.count / max(tot, 1e-9))
@@ -119,6 +137,9 @@ def main():
               f"{args.batch / max(p50, 1e-9):.0f} tok/s @p50)")
     metrics.close()
     print(f"generated[0][:16] = {gen[0, :16].tolist()}")
+    return ServeRun(tokens=gen, prefill_s=t_prefill,
+                    decode_compile_s=t_compile, decode_latency_s=s,
+                    compiled_decode=decode)
 
 
 if __name__ == "__main__":

@@ -42,8 +42,10 @@ dp > 1).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +59,28 @@ from ..optim.adamw import AdamWConfig
 from ..sharding import ctx, rules
 from ..training.train_step import (abstract_train_state, make_train_state,
                                    make_train_step)
+from .compile_cache import enable_compile_cache
 from .mesh import make_local_mesh
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """What a training run hands back to an in-process caller: the loss
+    and the mean wall time per step at every logged step, and the final
+    state.  The GSPMD path also gives its compile time and the compiled
+    step (whose HLO shows which kernels it runs)."""
+    steps: List[int] = dataclasses.field(default_factory=list)
+    losses: List[float] = dataclasses.field(default_factory=list)
+    step_times_s: List[float] = dataclasses.field(default_factory=list)
+    compile_s: Optional[float] = None
+    compiled: Any = None
+    mesh: Any = None
+    state: Any = None
+
+    def log(self, step: int, loss: float, step_time_s: float) -> None:
+        self.steps.append(step)
+        self.losses.append(loss)
+        self.step_times_s.append(step_time_s)
 
 
 def _pipeline_spec(args, cfg):
@@ -272,7 +295,7 @@ def _export_obs(args, cfg, spec, mesh, plan, stage_params, mask, toks,
           flush=True)
 
 
-def run_pipeline(args, cfg):
+def run_pipeline(args, cfg) -> TrainRun:
     """shard_map pipeline training: one physical stage (v chunk slots of
     layers for chunked schedules) per pipe-axis member; dp replicates
     the whole pipeline over a leading mesh axis (DESIGN.md §9)."""
@@ -334,8 +357,9 @@ def run_pipeline(args, cfg):
     stage_params, mask = HP.split_stage_params(params, cfg, spec)
     opt = AdamWConfig(lr=args.lr, total_steps=args.steps,
                       warmup_steps=max(args.steps // 20, 5))
+    # the state is donated: the step's output takes its buffers
     step_fn = jax.jit(HP.make_spmd_pipeline_train_step(
-        cfg, spec, mesh, opt, grad_sync=grad_sync))
+        cfg, spec, mesh, opt, grad_sync=grad_sync), donate_argnums=(0,))
     state = (stage_params, adamw.init_opt_state(stage_params),
              jnp.int32(0))
 
@@ -356,6 +380,7 @@ def run_pipeline(args, cfg):
                     priced_exposed_sync_s=sum(cost.exposed_sync),
                     priced_reshard_s=sum(cost.t_reshard))
     metrics = MetricsLogger(run_dir, meta=meta)
+    run = TrainRun(mesh=mesh)
 
     dcfg = DataConfig(batch_size=args.batch, seq_len=args.seq,
                       seed=1234 + args.seed)
@@ -370,27 +395,30 @@ def run_pipeline(args, cfg):
                                        args.seq)
         state, m = step_fn(state, mask, {"tokens": toks})
         if (i + 1) % args.log_every == 0 or i == 0:
+            row = {k: float(v) for k, v in m.items()}
             now = time.perf_counter()
             dt = now - t0
             tgs = tokens_per_step * (i + 1) / dt / need
-            row = {k: float(v) for k, v in m.items()}
+            step_time = (now - t_last) / (i + 1 - i_last)
+            run.log(i + 1, row["loss"], step_time)
             metrics.log(step=i + 1,
                         tokens_per_s=tokens_per_step * (i + 1) / dt,
-                        tgs=tgs,
-                        step_time_s=(now - t_last) / (i + 1 - i_last),
+                        tgs=tgs, step_time_s=step_time,
                         peak_bytes_in_use=device_memory_highwater(),
                         **row)
             t_last, i_last = now, i + 1
-            print(f"step {i + 1:5d} loss={float(m['loss']):.4f} "
+            print(f"step {i + 1:5d} loss={row['loss']:.4f} "
                   f"TGS={tgs:.0f}", flush=True)
     loader.close()
     if args.trace:
         _export_obs(args, cfg, spec, mesh, plan, state[0], mask, toks,
                     run_dir)
     metrics.close()
+    run.state = state
+    return run
 
 
-def main():
+def main(argv: Optional[List[str]] = None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_configs() + ["all"])
     ap.add_argument("--steps", type=int, default=50)
@@ -487,16 +515,16 @@ def main():
                     help="with --trace: flag a stage/replica whose "
                          "measured/priced ratio exceeds this factor × "
                          "the cohort median")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     name = canonical(args.arch)
     cfg = get_smoke_config(name) if args.smoke else get_config(name)
     print(f"arch={cfg.name} family={cfg.family} "
           f"params~{cfg.param_count() / 1e6:.1f}M devices={len(jax.devices())}")
 
     if args.pipeline_parallel > 1 or args.plan or args.search:
-        run_pipeline(args, cfg)
-        return
+        return run_pipeline(args, cfg)
     if args.trace:
         # the trace is a pipeline artifact (per-tick program re-drive);
         # the GSPMD path has no tick program to trace — refuse rather
@@ -519,32 +547,46 @@ def main():
             f"path data-parallelizes over the mesh's data axes by "
             f"itself)")
 
+    return run_gspmd(args, cfg)
+
+
+def run_gspmd(args, cfg) -> TrainRun:
+    """GSPMD data/model-parallel training over the local mesh.  The step
+    is compiled ahead of the loop, so its compile time is reported apart
+    from the step times."""
     mesh = make_local_mesh(model=args.model_parallel)
     opt = AdamWConfig(lr=args.lr, total_steps=args.steps,
                       warmup_steps=max(args.steps // 20, 5))
 
     with ctx.use_mesh(mesh):
-        state = make_train_state(cfg, jax.random.PRNGKey(args.seed))
         state_sh = rules.train_state_shardings(
-            jax.eval_shape(lambda: state), mesh,
-            hybrid=cfg.family == "hybrid")
-        state = jax.device_put(state, state_sh)
-        # no donation here: eagerly-initialized zeros/ones can alias the same
-        # buffer across leaves (jnp constant caching), which XLA rejects for
-        # donated args; the dry-run path (abstract inputs) does donate.
-        step_fn = jax.jit(make_train_step(cfg, opt, accum_steps=args.accum,
-                                          backend=args.backend))
-
-        dcfg = DataConfig(batch_size=args.batch, seq_len=args.seq,
-                          seed=1234 + args.seed)
-        loader = make_loader(cfg, dcfg)
-
+            abstract_train_state(cfg), mesh, hybrid=cfg.family == "hybrid")
+        state = jax.device_put(
+            make_train_state(cfg, jax.random.PRNGKey(args.seed)), state_sh)
         if args.ckpt_dir:
             from ..checkpointing.io import checkpoint_step
             if checkpoint_step(args.ckpt_dir) is not None:
                 state = load_checkpoint(args.ckpt_dir,
-                                        jax.eval_shape(lambda: state))
+                                        jax.eval_shape(lambda: state),
+                                        state_sh)
                 print(f"resumed from {args.ckpt_dir} at step {int(state.step)}")
+
+        dcfg = DataConfig(batch_size=args.batch, seq_len=args.seq,
+                          seed=1234 + args.seed)
+        loader = make_loader(cfg, dcfg)
+        batch = next(loader)
+        batch_sh = rules.batch_shardings(batch, mesh)
+        # the state is donated: the step's output takes its buffers, so
+        # HBM holds one copy of params + AdamW state, not two
+        step_fn = jax.jit(make_train_step(cfg, opt, accum_steps=args.accum,
+                                          backend=args.backend),
+                          in_shardings=(state_sh, batch_sh),
+                          out_shardings=(state_sh, None),
+                          donate_argnums=(0,))
+        t_c = time.perf_counter()
+        compiled = step_fn.lower(state, batch).compile()
+        compile_s = time.perf_counter() - t_c
+        print(f"compile: {compile_s:.1f}s", flush=True)
 
         from ..obs import MetricsLogger
         from ..obs.runtime import device_memory_highwater
@@ -552,25 +594,29 @@ def main():
             _run_dir(args, cfg),
             meta={"arch": cfg.name, "family": cfg.family, "mode": "gspmd",
                   "devices": len(jax.devices()), "batch": args.batch,
-                  "seq": args.seq})
+                  "seq": args.seq, "compile_s": compile_s})
+        run = TrainRun(compile_s=compile_s, compiled=compiled, mesh=mesh)
         tokens_per_step = args.batch * args.seq
         t0 = time.perf_counter()
         t_last, i_last = t0, 0
         for i in range(args.steps):
-            batch = next(loader)
-            state, m = step_fn(state, batch)
+            if i:
+                batch = next(loader)
+            state, m = compiled(state, jax.device_put(batch, batch_sh))
             if (i + 1) % args.log_every == 0 or i == 0:
+                loss = float(m["loss"])
                 now = time.perf_counter()
                 dt = now - t0
                 tgs = tokens_per_step * (i + 1) / dt / len(jax.devices())
+                step_time = (now - t_last) / (i + 1 - i_last)
+                run.log(i + 1, loss, step_time)
                 metrics.log(step=i + 1,
                             tokens_per_s=tokens_per_step * (i + 1) / dt,
-                            tgs=tgs,
-                            step_time_s=(now - t_last) / (i + 1 - i_last),
+                            tgs=tgs, step_time_s=step_time,
                             peak_bytes_in_use=device_memory_highwater(),
                             **{k: float(v) for k, v in m.items()})
                 t_last, i_last = now, i + 1
-                print(f"step {i + 1:5d} loss={float(m['loss']):.4f} "
+                print(f"step {i + 1:5d} loss={loss:.4f} "
                       f"lr={float(m['lr']):.2e} gnorm={float(m['grad_norm']):.2f} "
                       f"TGS={tgs:.0f}", flush=True)
             if args.ckpt_dir and args.ckpt_every and \
@@ -581,6 +627,8 @@ def main():
         if args.ckpt_dir:
             save_checkpoint(args.ckpt_dir, state, step=args.steps)
             print(f"checkpoint saved to {args.ckpt_dir}")
+    run.state = state
+    return run
 
 
 if __name__ == "__main__":

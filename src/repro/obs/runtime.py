@@ -33,16 +33,14 @@ __all__ = ["trace_spmd_pipeline", "device_memory_highwater"]
 
 def device_memory_highwater() -> Optional[int]:
     """Max ``peak_bytes_in_use`` across local devices, or None where the
-    backend keeps no memory stats (host CPU platforms)."""
-    try:
-        peaks = []
-        for d in jax.local_devices():
-            stats = d.memory_stats()
-            if stats and stats.get("peak_bytes_in_use") is not None:
-                peaks.append(int(stats["peak_bytes_in_use"]))
-        return max(peaks) if peaks else None
-    except Exception:
-        return None
+    backend keeps no memory stats (host CPU platforms return None).  Any
+    other failure to read them propagates."""
+    peaks = []
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        if stats and stats.get("peak_bytes_in_use") is not None:
+            peaks.append(int(stats["peak_bytes_in_use"]))
+    return max(peaks) if peaks else None
 
 
 def trace_spmd_pipeline(cfg, spec, mesh, stage_params, mask, tokens, *,

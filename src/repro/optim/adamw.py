@@ -39,7 +39,9 @@ def lr_at(cfg: AdamWConfig, step):
 
 
 def init_opt_state(params: PyTree) -> PyTree:
-    f32 = lambda p: p.astype(jnp.float32)
+    # a copy even where the param is already fp32: a master that aliases
+    # its param cannot be donated alongside it
+    f32 = lambda p: jnp.array(p, jnp.float32, copy=True)
     zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
     return {
         "master": jax.tree.map(f32, params),

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.optim.adamw import AdamWConfig
 from repro.sharding import ctx, rules
@@ -19,15 +20,8 @@ from repro.training.train_step import make_train_state, make_train_step
 
 
 def main():
-    if not hasattr(jax, "shard_map"):
-        # partial-manual shard_map (manual over data, GSPMD-auto over
-        # model) hard-crashes XLA (IsManualSubgroup CHECK) on legacy
-        # jaxlibs — the NOTE in repro.training.manual_dp
-        print("MANUAL_DP_SKIP: partial-manual shard_map needs jax>=0.8")
-        return
-
     cfg = dataclasses.replace(get_smoke_config("granite_8b"), dtype="float32")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_local_mesh(model=2, data=4)
     opt = AdamWConfig(lr=1e-3, warmup_steps=0, total_steps=10)
     key = jax.random.PRNGKey(0)
     batch = {"tokens": jax.random.randint(key, (8, 32), 0, cfg.vocab_size)}

@@ -37,6 +37,7 @@ from repro.core.schedules import get_schedule
 from repro.models import model as M
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
+from repro.launch.mesh import auto_mesh
 
 DP, B = 2, 4          # dp replicas × microbatches per replica
 
@@ -68,8 +69,8 @@ def main():
     tokens = jax.random.randint(key, (DP * B, mb, S_seq), 0, cfg.vocab_size)
     phys = (2, 2)
 
-    mesh2d = jax.make_mesh((2, 2), ("pipe", "tp"))
-    mesh3d = jax.make_mesh((2, 2, 2), ("dp", "pipe", "tp"))
+    mesh2d = auto_mesh((2, 2), ("pipe", "tp"))
+    mesh3d = auto_mesh((2, 2, 2), ("dp", "pipe", "tp"))
 
     # dp=1 references: ONE pipeline streaming the whole global batch
     # (per schedule — chunked schedules lay parameters out differently)
@@ -205,7 +206,7 @@ def main():
     sspec = HP.from_plan(r.plan, execute_dp=True,
                          execute_tp=len(tps) == 1)
     assert sspec.data_parallel == DP
-    smesh = jax.make_mesh((DP, sspec.num_stages, sspec.tensor_parallel)
+    smesh = auto_mesh((DP, sspec.num_stages, sspec.tensor_parallel)
                           if sspec.tensor_parallel > 1
                           else (DP, sspec.num_stages),
                           ("dp", "pipe", "tp")

@@ -40,6 +40,7 @@ from repro.models import model as M
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
 from repro.sharding import rules
+from repro.launch.mesh import auto_mesh
 
 
 def _monolithic_ref(params, cfg, tokens):
@@ -85,7 +86,7 @@ def main():
     tokens = jax.random.randint(key, (b, mb, S), 0, cfg.vocab_size)
     ref = _monolithic_ref(params, cfg, tokens)
 
-    mesh8 = jax.make_mesh((8,), ("pipe",))
+    mesh8 = auto_mesh((8,), ("pipe",))
 
     # ---- asymmetric grouped pipeline: tp = 4, 2, 1, 1 over 8 devices ----
     spec = HP.PipelineSpec(4, (1, 1, 1, 1), microbatches=b,
@@ -113,7 +114,7 @@ def main():
     sp_gu, mask_gu = HP.split_stage_params(params, cfg, spec_gu)
     loss_gu = float(HP.make_spmd_pipeline_loss(cfg, spec_gu, mesh8)(
         sp_gu, mask_gu, tokens))
-    mesh2d = jax.make_mesh((4, 2), ("pipe", "tp"))
+    mesh2d = auto_mesh((4, 2), ("pipe", "tp"))
     spec_2d = HP.PipelineSpec(4, (1, 1, 1, 1), microbatches=b,
                               tensor_parallel=2)
     sp_2d, mask_2d = HP.split_stage_params(params, cfg, spec_2d)

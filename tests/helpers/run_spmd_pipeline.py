@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.core import heteropp as HP
 from repro.models import model as M
+from repro.launch.mesh import auto_mesh
 
 
 def main():
@@ -34,7 +35,7 @@ def main():
     b, mb, S = 4, 2, 32
     tokens = jax.random.randint(key, (b, mb, S), 0, cfg.vocab_size)
 
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = auto_mesh((4,), ("pipe",))
     # 4 stages over 2 layers won't sum; use padded non-uniform split of 2
     phys = (1, 0, 0, 1)
     spec = HP.PipelineSpec(4, phys, microbatches=b)
@@ -44,9 +45,7 @@ def main():
     for schedule in ("1f1b", "gpipe", "zb_h1"):
         loss_fn = HP.make_spmd_pipeline_loss(cfg, spec, mesh, remat=True,
                                              schedule=schedule)
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else _null():
-            losses[schedule] = float(loss_fn(stage_params, mask, tokens))
+        losses[schedule] = float(loss_fn(stage_params, mask, tokens))
     loss = losses["1f1b"]
     # single-chunk schedules share the diagonal-stream injection order:
     # identical program, bit-identical loss
@@ -61,9 +60,7 @@ def main():
             schedule=schedule, n_chunks=v)
         csp, cmask = HP.split_stage_params(params, cfg, cspec)
         loss_fn = HP.make_spmd_pipeline_loss(cfg, cspec, mesh, remat=True)
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else _null():
-            losses[schedule] = float(loss_fn(csp, cmask, tokens))
+        losses[schedule] = float(loss_fn(csp, cmask, tokens))
     assert losses["interleaved"] == loss == losses["zb_v"] \
         == losses["wave"], losses
     print(f"chunked losses bit-exact vs single-chunk: "
@@ -111,9 +108,7 @@ def main():
     assert pspec.total_layers == cfg.num_layers
     psp, pmask = HP.split_stage_params(params, cfg, pspec)
     loss_fn = HP.make_spmd_pipeline_loss(cfg, pspec, mesh, remat=True)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-            else _null():
-        plan_loss = float(loss_fn(psp, pmask, tokens))
+    plan_loss = float(loss_fn(psp, pmask, tokens))
     plan_sim = []
     for i in range(b):
         logits, _ = HP.simulate_pipeline_forward(params, cfg, pspec,
@@ -143,14 +138,6 @@ def main():
     assert np.isfinite(gn2) and gn2 > 0
     print(f"grad_abs_sum={gn:.3e} chunked={gn2:.3e}")
     print("OK")
-
-
-class _null:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False
 
 
 if __name__ == "__main__":

@@ -33,6 +33,7 @@ from repro.configs import get_smoke_config
 from repro.core import heteropp as HP
 from repro.core.schedules import get_schedule
 from repro.models import model as M
+from repro.launch.mesh import auto_mesh
 
 
 def _monolithic_ref(params, cfg, tokens):
@@ -52,8 +53,8 @@ def main():
     b, mb, S = 4, 2, 32
     tokens = jax.random.randint(key, (b, mb, S), 0, cfg.vocab_size)
 
-    mesh1d = jax.make_mesh((2,), ("pipe",))
-    mesh2d = jax.make_mesh((2, 2), ("pipe", "tp"))
+    mesh1d = auto_mesh((2,), ("pipe",))
+    mesh2d = auto_mesh((2, 2), ("pipe", "tp"))
 
     # tp=1 reference on the 1-D pipe mesh
     phys = (2, 2)
@@ -89,7 +90,7 @@ def main():
     np.testing.assert_allclose(losses["1f1b"], loss1, rtol=1e-5)
 
     # all 8 devices: pipe=4 × tp=2, zb_v V placement
-    mesh8 = jax.make_mesh((4, 2), ("pipe", "tp"))
+    mesh8 = auto_mesh((4, 2), ("pipe", "tp"))
     spec8 = HP.PipelineSpec(
         4, HP.chunk_layer_counts((1, 1, 1, 1), "zb_v"), microbatches=b,
         schedule="zb_v", n_chunks=2, tensor_parallel=2)

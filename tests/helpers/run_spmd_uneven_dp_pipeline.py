@@ -44,6 +44,7 @@ from repro.core.dataparallel import pad_index_map
 from repro.models import model as M
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
+from repro.launch.mesh import auto_mesh
 
 DOMAIN = (5, 3)                # dp=2: pacing replica 0, light replica 1
 TOTAL = sum(DOMAIN)
@@ -69,8 +70,8 @@ def main():
     tokens = jax.random.randint(key, (TOTAL, mb, S_seq), 0, cfg.vocab_size)
     phys = (2, 2)
 
-    mesh2d = jax.make_mesh((2, 2), ("pipe", "tp"))
-    mesh3d = jax.make_mesh((2, 2, 2), ("dp", "pipe", "tp"))
+    mesh2d = auto_mesh((2, 2), ("pipe", "tp"))
+    mesh3d = auto_mesh((2, 2, 2), ("dp", "pipe", "tp"))
 
     # dp=1 reference: ONE pipeline streaming the whole global batch
     spec1 = HP.PipelineSpec(2, phys, microbatches=TOTAL,

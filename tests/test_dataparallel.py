@@ -18,6 +18,7 @@ from repro.core.dataparallel import (GradBuckets, bucketize,
                                      check_memory_caps, domain_cost,
                                      partition, sync_time,
                                      zero1_scatter_dim)
+from repro.launch.mesh import auto_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -571,7 +572,7 @@ def test_spmd_dp_pipeline_in_process():
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 2, 16), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((2, 2, 2), ("dp", "pipe", "tp"))
+    mesh = auto_mesh((2, 2, 2), ("dp", "pipe", "tp"))
     spec = HP.PipelineSpec(2, (1, 1), microbatches=2, tensor_parallel=2,
                            data_parallel=2)
     sp, mask = HP.split_stage_params(params, cfg, spec)

@@ -15,6 +15,7 @@ from conftest import make_batch
 from repro.configs import get_config, get_smoke_config
 from repro.core import chips, heteroauto, heteropp as HP
 from repro.models import model as M
+from repro.launch.mesh import auto_mesh
 
 
 @pytest.mark.parametrize("arch,splits", [
@@ -116,7 +117,7 @@ def test_spmd_wave_pipeline_in_process():
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 2, 16), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = auto_mesh((4,), ("pipe",))
     phys = (1, 0, 0, 1)
     spec = HP.PipelineSpec(4, HP.chunk_layer_counts(phys, "wave"),
                            microbatches=4, schedule="wave", n_chunks=4)
@@ -152,8 +153,6 @@ def test_manual_dp_zero1_subprocess():
     r = subprocess.run([sys.executable, script], capture_output=True,
                        text=True, timeout=600, env=env, cwd=root)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    if "MANUAL_DP_SKIP" in r.stdout:
-        pytest.skip("partial-manual shard_map unsupported on this jax")
     assert "MANUAL_DP_OK" in r.stdout
 
 
@@ -222,7 +221,7 @@ def test_spmd_grouped_tp_pipeline_in_process():
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 2, 16), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = auto_mesh((4,), ("pipe",))
     spec = HP.PipelineSpec(3, (1, 1, 1), microbatches=2,
                            stage_tp=(2, 1, 1))
     assert spec.reshard == ("sr_ag", "none")
@@ -318,7 +317,7 @@ def test_spmd_tp_pipeline_in_process():
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 2, 16), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((2, 2), ("pipe", "tp"))
+    mesh = auto_mesh((2, 2), ("pipe", "tp"))
     spec = HP.PipelineSpec(2, (1, 1), microbatches=2, tensor_parallel=2)
     sp, mask = HP.split_stage_params(params, cfg, spec)
     loss = float(HP.make_spmd_pipeline_loss(cfg, spec, mesh)(

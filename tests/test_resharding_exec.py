@@ -164,8 +164,9 @@ def test_reshard_equivalence_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.resharding import reshard
+        from repro.launch.mesh import auto_mesh
         for pipe, tp, dt in ((2, 4, jnp.float32), (4, 2, jnp.bfloat16)):
-            mesh = jax.make_mesh((pipe, tp), ("pipe", "tp"))
+            mesh = auto_mesh((pipe, tp), ("pipe", "tp"))
             x = jax.random.normal(
                 jax.random.PRNGKey(0), (pipe, 4, 16)).astype(dt)
             x = jax.device_put(

@@ -93,7 +93,8 @@ def test_reshard_shard_map_equivalence():
         force_host_device_count(8)
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.resharding import reshard
-        mesh = jax.make_mesh((2, 4), ("pipe", "tp"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("pipe", "tp"))
         x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 16))
         from jax.sharding import NamedSharding, PartitionSpec as P
         x = jax.device_put(x, NamedSharding(mesh, P("pipe", None, "tp")))

@@ -44,6 +44,7 @@ def test_spmd_uneven_dp_pipeline_in_process():
     import numpy as np
     from repro.configs import get_smoke_config
     from repro.core import heteropp as HP
+    from repro.launch.mesh import auto_mesh
     from repro.models import model as M
 
     cfg = dataclasses.replace(get_smoke_config("granite_8b"),
@@ -51,7 +52,7 @@ def test_spmd_uneven_dp_pipeline_in_process():
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 2, 16), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((2, 2, 2), ("dp", "pipe", "tp"))
+    mesh = auto_mesh((2, 2, 2), ("dp", "pipe", "tp"))
     spec = HP.PipelineSpec(2, (1, 1), microbatches=3, tensor_parallel=2,
                            data_parallel=2, batch_domain=(3, 1))
     assert spec.total_microbatches == 4

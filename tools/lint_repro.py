@@ -1,12 +1,11 @@
 #!/usr/bin/env python
-"""Repo-structure lint: AST checks for the two compat boundaries the
-codebase routes through single modules (CI's ``analysis`` job runs this
+"""Repo-structure lint: AST checks for the two boundaries the codebase
+routes through single modules (CI's ``analysis`` job runs this
 on every push; ``python tools/lint_repro.py`` locally).
 
-* ``jax.experimental.shard_map`` may only be imported in
-  ``src/repro/core/jax_compat.py`` — every other module must use the
-  ``jax_compat.shard_map`` shim, which papers over the
-  legacy/stable API split (DESIGN.md §9).
+* ``jax.experimental.shard_map`` (the legacy API) is imported nowhere:
+  every module uses ``repro.core.jax_compat.shard_map``, which fixes
+  the manual-axes signature over ``jax.shard_map`` (DESIGN.md §2).
 * The ``XLA_FLAGS --xla_force_host_platform_device_count`` env prepend
   may only appear in ``src/repro/launch/hostdevices.py`` — scattered
   prepends fight each other (last writer wins after jax initializes),
@@ -22,7 +21,6 @@ import os
 import sys
 from typing import List
 
-SHARD_MAP_HOME = os.path.join("src", "repro", "core", "jax_compat.py")
 HOSTDEV_HOME = os.path.join("src", "repro", "launch", "hostdevices.py")
 ENV_NEEDLE = "xla_force_host_platform_device_count"
 
@@ -60,13 +58,11 @@ def lint_file(path: str) -> List[str]:
         return [f"{path}:{e.lineno}: syntax error: {e.msg}"]
     rel = os.path.relpath(path)
     problems = []
-    if not rel.endswith(SHARD_MAP_HOME):
-        for node in ast.walk(tree):
-            if _is_shard_map_import(node):
-                problems.append(
-                    f"{rel}:{node.lineno}: jax.experimental.shard_map "
-                    f"imported outside {SHARD_MAP_HOME} — use "
-                    "repro.core.jax_compat.shard_map")
+    for node in ast.walk(tree):
+        if _is_shard_map_import(node):
+            problems.append(
+                f"{rel}:{node.lineno}: legacy jax.experimental.shard_map "
+                "imported — use repro.core.jax_compat.shard_map")
     if not rel.endswith(HOSTDEV_HOME):
         for lineno in _env_prepend_lines(tree, source):
             problems.append(
