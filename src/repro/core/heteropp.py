@@ -72,6 +72,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import attention, layers, model as M, transformer as tfm
 from ..models.config import ModelConfig
+from ..obs import scopes
 from ..optim import adamw
 
 PyTree = Any
@@ -596,7 +597,8 @@ def _stage_forward(blocks, mask_row, cfg, x, kind: str, remat: bool,
         return x, jnp.where(valid, aux1, 0.0)
 
     body = jax.checkpoint(one) if remat else one
-    x, auxs = jax.lax.scan(body, x, (blocks, mask_row))
+    with jax.named_scope(scopes.LAYERS):
+        x, auxs = jax.lax.scan(body, x, (blocks, mask_row))
     return x, jnp.sum(auxs)
 
 
@@ -675,6 +677,7 @@ def _grouped_replica_core(cfg: ModelConfig, spec: PipelineSpec, mesh: Mesh,
     d = cfg.d_model
     dtype = layers.dtype_of(cfg)
 
+    @jax.named_scope(scopes.PIPE_TICK)
     def tick_step(stage_params, mask, tokens, carry, row):
         # One tick of the grouped SPMD program, device-local (inside
         # shard_map): shared by the lax.scan below and the host-driven
@@ -724,8 +727,9 @@ def _grouped_replica_core(cfg: ModelConfig, spec: PipelineSpec, mesh: Mesh,
         # outputs, then each device mixes its sources' contributions
         # (disjoint sr_ag shards sum to the full activation; naive
         # rows pick their matched source) — the next tick's x_prev
-        g = jax.lax.all_gather(y * srow.astype(y.dtype), axis)
-        x_prev2 = jnp.tensordot(rrow.astype(y.dtype), g, axes=(0, 0))
+        with jax.named_scope(scopes.PIPE_SEND):
+            g = jax.lax.all_gather(y * srow.astype(y.dtype), axis)
+            x_prev2 = jnp.tensordot(rrow.astype(y.dtype), g, axes=(0, 0))
         return (x_prev2, loss_acc, aux_acc, denom)
 
     def replica_fn(stage_params, mask, tokens):
@@ -858,6 +862,7 @@ def _pipeline_replica_core(cfg: ModelConfig, spec: PipelineSpec, mesh: Mesh,
     d = cfg.d_model
     dtype = layers.dtype_of(cfg)
 
+    @jax.named_scope(scopes.PIPE_TICK)
     def tick_step(stage_params, mask, tokens, carry, row):
         # One tick of the SPMD program, device-local (inside shard_map):
         # shared by the lax.scan below and the host-driven per-tick
@@ -910,20 +915,18 @@ def _pipeline_replica_core(cfg: ModelConfig, spec: PipelineSpec, mesh: Mesh,
         denom = denom + jnp.where(take, jnp.sum(lmask), 0.0)
         aux_acc = aux_acc + jnp.where(active, aux, 0.0)
         # shift activations one hop each way for the next tick
-        if needs_prev:
-            perm_f = [(i, (i + 1) % nstages)
-                      for i in range(nstages if wraps_prev
-                                     else nstages - 1)]
-            x_prev2 = jax.lax.ppermute(y, axis, perm_f)
-        else:
-            x_prev2 = x_prev
-        if needs_next:
-            perm_b = [(i, i - 1) for i in range(1, nstages)]
-            if wraps_next:
-                perm_b.append((0, nstages - 1))
-            x_next2 = jax.lax.ppermute(y, axis, perm_b)
-        else:
-            x_next2 = x_next
+        x_prev2, x_next2 = x_prev, x_next
+        with jax.named_scope(scopes.PIPE_SEND):
+            if needs_prev:
+                perm_f = [(i, (i + 1) % nstages)
+                          for i in range(nstages if wraps_prev
+                                         else nstages - 1)]
+                x_prev2 = jax.lax.ppermute(y, axis, perm_f)
+            if needs_next:
+                perm_b = [(i, i - 1) for i in range(1, nstages)]
+                if wraps_next:
+                    perm_b.append((0, nstages - 1))
+                x_next2 = jax.lax.ppermute(y, axis, perm_b)
         y_loc2 = y if needs_local else y_loc
         return (x_prev2, x_next2, y_loc2, loss_acc, aux_acc, denom)
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import time
 from typing import Any, Dict, Iterator, Optional
 
 import jax
@@ -18,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.config import ModelConfig
+from ..obs import scopes
+from ..obs.metrics import MetricsRegistry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,7 +84,13 @@ def _unit_noise(shape, seed) -> np.ndarray:
 
 
 class DataLoader:
-    """Host-side prefetching iterator that device_puts with a sharding."""
+    """Host-side prefetching iterator that device_puts with a sharding.
+
+    It counts what it does (``counters()``): ``batches`` handed out,
+    ``queue_wait_s`` the consumer spent blocked on the prefetch queue,
+    ``put_s`` in ``device_put``, and ``produce_s`` the worker spent
+    making batches.  The same three phases are profiler spans
+    (``obs.scopes.DATA_SPANS``) in a profiler trace."""
 
     def __init__(self, source: SyntheticTokens, shardings: Optional[Any] = None,
                  prefetch: int = 2):
@@ -89,13 +98,28 @@ class DataLoader:
         self.shardings = shardings
         self._q: queue.Queue = queue.Queue(maxsize=max(prefetch, 1))
         self._stop = threading.Event()
+        self._metrics = MetricsRegistry()
+        # each counter has one writer: produce_s the worker, the rest
+        # the consumer
+        self._batches = self._metrics.counter("batches")
+        self._queue_wait = self._metrics.counter("queue_wait_s")
+        self._put = self._metrics.counter("put_s")
+        self._produce = self._metrics.counter("produce_s")
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
 
+    def counters(self) -> Dict[str, float]:
+        """Snapshot of the loader's counters since it was made."""
+        return self._metrics.snapshot()
+
     def _worker(self):
+        span = jax.profiler.TraceAnnotation
         try:
             while not self._stop.is_set():
-                batch = self.source.next_batch()
+                t0 = time.perf_counter()
+                with span(scopes.DATA_PRODUCE):
+                    batch = self.source.next_batch()
+                self._produce.inc(time.perf_counter() - t0)
                 while not self._stop.is_set():
                     try:
                         self._q.put(batch, timeout=1.0)
@@ -110,12 +134,22 @@ class DataLoader:
         return self
 
     def __next__(self) -> Dict[str, jnp.ndarray]:
-        batch = self._q.get()
+        span = jax.profiler.TraceAnnotation
+        t0 = time.perf_counter()
+        with span(scopes.DATA_QUEUE_WAIT):
+            batch = self._q.get()
+        t1 = time.perf_counter()
+        self._queue_wait.inc(t1 - t0)
         if isinstance(batch, BaseException):
             raise RuntimeError("data worker failed") from batch
-        if self.shardings is not None:
-            return jax.device_put(batch, self.shardings)
-        return jax.tree.map(jnp.asarray, batch)
+        with span(scopes.DATA_DEVICE_PUT):
+            if self.shardings is not None:
+                batch = jax.device_put(batch, self.shardings)
+            else:
+                batch = jax.tree.map(jnp.asarray, batch)
+        self._put.inc(time.perf_counter() - t1)
+        self._batches.inc()
+        return batch
 
     def close(self):
         self._stop.set()

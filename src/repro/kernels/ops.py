@@ -24,6 +24,7 @@ from . import flash_decode as _fd
 from . import ref as _ref
 from . import rmsnorm as _rn
 from . import ssd_scan as _ssd
+from ..obs import scopes
 
 NEG_INF = _ref.NEG_INF
 
@@ -77,6 +78,7 @@ def _fa_core_bwd(causal, window, q_offset, bq, bk, res, g):
 _fa_core.defvjp(_fa_core_fwd, _fa_core_bwd)
 
 
+@jax.named_scope(scopes.ATTENTION_CORE)
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_offset"))
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0):
     """q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd) — expands GQA internally."""
@@ -167,6 +169,7 @@ def _ssd_core_bwd(chunk, res, g):
 _ssd_core.defvjp(_ssd_core_fwd, _ssd_core_bwd)
 
 
+@jax.named_scope(scopes.SSD_CORE)
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk=128, initial_state=None):
     """Chunked SSD; signature mirrors models.ssm.ssd_chunked."""

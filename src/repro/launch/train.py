@@ -390,10 +390,11 @@ def run_pipeline(args, cfg) -> TrainRun:
     t0 = time.perf_counter()
     t_last, i_last = t0, 0
     for i in range(args.steps):
-        batch = next(loader)
-        toks = batch["tokens"].reshape(total_mb, args.batch // total_mb,
-                                       args.seq)
-        state, m = step_fn(state, mask, {"tokens": toks})
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            batch = next(loader)
+            toks = batch["tokens"].reshape(total_mb, args.batch // total_mb,
+                                           args.seq)
+            state, m = step_fn(state, mask, {"tokens": toks})
         if (i + 1) % args.log_every == 0 or i == 0:
             row = {k: float(v) for k, v in m.items()}
             now = time.perf_counter()
@@ -600,9 +601,10 @@ def run_gspmd(args, cfg) -> TrainRun:
         t0 = time.perf_counter()
         t_last, i_last = t0, 0
         for i in range(args.steps):
-            if i:
-                batch = next(loader)
-            state, m = compiled(state, jax.device_put(batch, batch_sh))
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                if i:
+                    batch = next(loader)
+                state, m = compiled(state, jax.device_put(batch, batch_sh))
             if (i + 1) % args.log_every == 0 or i == 0:
                 loss = float(m["loss"])
                 now = time.perf_counter()

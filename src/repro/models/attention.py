@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from . import layers
+from ..obs import scopes
 from ..sharding import ctx as shctx
 from ..sharding.ctx import constrain
 
@@ -151,6 +152,7 @@ def _attend_chunked(q, k, v, q_pos, k_pos, causal, window, prefix_len, scale,
     return outs.swapaxes(0, 1).reshape(B, Sq, H, hd)
 
 
+@jax.named_scope(scopes.ATTENTION_CORE)
 def attend(q, k, v, *, q_pos, k_pos, causal=True, window=0, prefix_len=0,
            softcap=0.0, backend="auto"):
     """Full attention dispatch.  q:(B,Sq,H,hd), k/v:(B,Sk,H,hd).
@@ -186,6 +188,7 @@ def attend(q, k, v, *, q_pos, k_pos, causal=True, window=0, prefix_len=0,
 # forward (training / prefill) self-attention
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.ATTENTION)
 def self_attention(params, cfg, x, *, positions=None, causal=True,
                    prefix_len=0, rope=True, window=None, backend="auto"):
     B, S, _ = x.shape

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import scopes
+
 
 def dtype_of(cfg) -> jnp.dtype:
     return jnp.dtype(cfg.dtype)
@@ -73,6 +75,7 @@ def init_mlp(key, d: int, ff: int, kind: str, dtype=jnp.bfloat16):
     }
 
 
+@jax.named_scope(scopes.MLP)
 def apply_mlp(params, x, kind: str):
     h = x @ params["wi"]
     if kind == "swiglu" or kind == "glu":
@@ -116,6 +119,7 @@ def init_embeddings(key, cfg, dtype=jnp.bfloat16):
     return p
 
 
+@jax.named_scope(scopes.EMBED)
 def embed_tokens(params, tokens):
     return jnp.take(params["tok"], tokens, axis=0)
 

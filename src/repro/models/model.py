@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from . import attention, layers, ssm as ssm_lib, transformer as tfm
 from .config import ModelConfig
+from ..obs import scopes
 from ..sharding.ctx import constrain
 
 PyTree = Any
@@ -175,6 +176,7 @@ def _ce_chunk(embed_params, x_c, t_c, m_c):
     return jnp.sum(ce)
 
 
+@jax.named_scope(scopes.LOSS_HEAD)
 def chunked_ce(embed_params, hidden, targets, mask, chunk=LOSS_CHUNK):
     """Scan over sequence chunks with remat: peak memory = one chunk's
     logits instead of the full (B, S, V) fp32 tensor."""

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from . import layers
+from ..obs import scopes
 from ..sharding.ctx import constrain
 
 
@@ -68,6 +69,7 @@ def _segsum(a):
     return jnp.where(mask, diff, -jnp.inf)
 
 
+@jax.named_scope(scopes.SSD_CORE)
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
     """Chunked SSD.
 
@@ -140,6 +142,7 @@ def ssd_recurrent_step(state, x_t, dt_t, A, B_t, C_t):
 # full Mamba2 block
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.SSD)
 def mamba2_forward(params, cfg, u, *, initial_state=None, backend="auto"):
     """u: (B, S, d) -> (y (B, S, d), final ssm state)."""
     B, S, d = u.shape

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import attention, layers, moe as moe_lib, ssm as ssm_lib
+from ..obs import scopes
 from ..sharding.ctx import constrain
 
 PyTree = Any
@@ -106,7 +107,8 @@ def run_stacked(blocks: PyTree, cfg, x, kind: str, *, remat=True,
         return x, jnp.asarray(aux, jnp.float32)
 
     body = _maybe_remat(one, remat, remat_policy)
-    x, auxs = jax.lax.scan(lambda c, p: body(c, p), x, blocks)
+    with jax.named_scope(scopes.LAYERS):
+        x, auxs = jax.lax.scan(lambda c, p: body(c, p), x, blocks)
     x = cblk(x)
     return x, jnp.sum(auxs)
 

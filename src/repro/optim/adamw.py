@@ -13,6 +13,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import scopes
+
 PyTree = Any
 
 
@@ -55,6 +57,7 @@ def global_norm(tree: PyTree):
                         for x in jax.tree.leaves(tree)))
 
 
+@jax.named_scope(scopes.OPTIMIZER)
 def apply_update(opt_cfg: AdamWConfig, opt_state: PyTree, grads: PyTree,
                  step, params: PyTree, *,
                  grad_norm=None) -> tuple[PyTree, PyTree, dict]:
