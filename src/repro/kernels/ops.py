@@ -6,9 +6,12 @@ model path is testable on CPU.  Wrappers handle GQA expansion, sequence
 padding to block multiples, and dtype plumbing.
 
 Training kernels (``flash_attention``, ``ssd_scan``, ``rmsnorm``) carry a
-``custom_vjp``: forward runs the Pallas kernel, backward differentiates the
-``ref.py`` oracle (recompute-style, XLA-fused) — so ``jax.grad`` through a
-``backend="pallas"`` model works without a hand-written backward kernel.
+``custom_vjp``: forward runs the Pallas kernel, backward differentiates a
+jnp form of it (recompute-style, XLA-fused) — the ``ref.py`` oracle for
+``flash_attention`` and ``rmsnorm``, the chunked SSD of ``ssd_chunked.py``
+(the kernel's own algorithm, as matmuls over chunks) for ``ssd_scan`` — so
+``jax.grad`` through a ``backend="pallas"`` model works without a
+hand-written backward kernel.
 ``flash_decode`` is inference-only and defines no VJP.
 """
 from __future__ import annotations
@@ -24,6 +27,7 @@ from . import flash_decode as _fd
 from . import ref as _ref
 from . import rmsnorm as _rn
 from . import ssd_scan as _ssd
+from .ssd_chunked import ssd_chunked
 from ..obs import scopes
 
 NEG_INF = _ref.NEG_INF
@@ -159,10 +163,10 @@ def _ssd_core_fwd(x, dt, A, Bm, Cm, chunk):
 
 def _ssd_core_bwd(chunk, res, g):
     x, dt, A, Bm, Cm = res
-    # backward through the sequential-scan oracle: same recurrence the
-    # kernel computes, so gradients are exact for the zero-state path
-    _, vjp = jax.vjp(lambda x, dt, A, Bm, Cm: _ref.ssd_ref(x, dt, A, Bm, Cm),
-                     x, dt, A, Bm, Cm)
+    # backward through the chunked form the kernel runs (same chunk):
+    # chunk x chunk matmuls and a recurrence over S / chunk chunk states,
+    # in float32; g carries the cotangents of y and final_state
+    _, vjp = jax.vjp(lambda *a: ssd_chunked(*a, chunk), x, dt, A, Bm, Cm)
     return vjp(g)
 
 
@@ -172,7 +176,7 @@ _ssd_core.defvjp(_ssd_core_fwd, _ssd_core_bwd)
 @jax.named_scope(scopes.SSD_CORE)
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk=128, initial_state=None):
-    """Chunked SSD; signature mirrors models.ssm.ssd_chunked."""
+    """Chunked SSD; signature mirrors ssd_chunked.ssd_chunked."""
     del initial_state  # kernel starts from zero state (prefill/train path)
     return _ssd_core(x, dt, A, Bm, Cm, chunk)
 

@@ -72,7 +72,7 @@ def decode_attention_ref(q, k, v, pos, *, window=0, softcap=0.0,
 
 def ssd_ref(x, dt, A, Bm, Cm, initial_state=None):
     """Sequential (non-chunked) SSD recurrence — the simplest possible
-    ground truth for the ssd_scan kernel AND for models/ssm.ssd_chunked.
+    ground truth for the ssd_scan kernel AND for kernels/ssd_chunked.
 
     x: (b, S, h, p); dt: (b, S, h); A: (h,); Bm/Cm: (b, S, g, n).
     Returns (y (b, S, h, p), final_state (b, h, p, n)).

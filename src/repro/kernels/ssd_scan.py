@@ -10,7 +10,9 @@ carries the recurrence across the sequence, exactly like the flash kernel
 carries softmax statistics.  Block shapes: chunk × headdim and
 chunk × state tiles (chunk defaults to 128 — lane-aligned).
 
-Oracle: ``repro.kernels.ref.ssd_ref`` (sequential recurrence).
+Oracle: ``repro.kernels.ref.ssd_ref`` (sequential recurrence).  The
+backward (``ops.ssd_scan``'s custom VJP) differentiates
+``repro.kernels.ssd_chunked``, the same chunked algorithm in jnp.
 """
 from __future__ import annotations
 

@@ -1,9 +1,9 @@
 """Mamba2 (SSD — state-space duality) block, chunked-parallel + recurrent.
 
 Training/prefill uses the chunked SSD form of arXiv:2405.21060 (quadratic
-within a chunk, linear across chunks); decode is the O(1) recurrent update.
-A Pallas TPU kernel for the intra-chunk compute lives in
-``repro.kernels.ssd_scan`` with this module's math as its oracle.
+within a chunk, linear across chunks), ``repro.kernels.ssd_chunked``, or
+the Pallas kernel ``repro.kernels.ssd_scan`` that runs the same algorithm;
+decode is the O(1) recurrent update.
 """
 from __future__ import annotations
 
@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from . import layers
+from ..kernels import ssd_chunked as _ssd_chunked
 from ..obs import scopes
 from ..sharding.ctx import constrain
 
@@ -58,68 +59,8 @@ def _causal_conv(u, w, b):
     return out + b
 
 
-def _segsum(a):
-    """Stable segment-sum: a (..., l) -> (..., l, l) with
-    out[i, j] = sum_{j < t <= i} a[t], -inf above diagonal."""
-    l = a.shape[-1]
-    cs = jnp.cumsum(a, axis=-1)
-    diff = cs[..., :, None] - cs[..., None, :]
-    i = jnp.arange(l)
-    mask = i[:, None] >= i[None, :]
-    return jnp.where(mask, diff, -jnp.inf)
-
-
-@jax.named_scope(scopes.SSD_CORE)
-def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
-    """Chunked SSD.
-
-    x:  (b, S, h, p)   inputs per head
-    dt: (b, S, h)      positive step sizes (already softplus'd)
-    A:  (h,)           negative decay rates
-    Bm: (b, S, g, n)   input matrices  (g groups broadcast over heads)
-    Cm: (b, S, g, n)   output matrices
-    Returns (y (b,S,h,p), final_state (b,h,p,n)).
-    """
-    b, S, h, p = x.shape
-    g, n = Bm.shape[2], Bm.shape[3]
-    assert S % chunk == 0, (S, chunk)
-    nc = S // chunk
-    rep = h // g
-
-    xd = (x * dt[..., None]).astype(jnp.float32)
-    Ad = (A[None, None, :] * dt).astype(jnp.float32)          # (b,S,h)
-
-    # chunked views
-    xc = xd.reshape(b, nc, chunk, h, p)
-    Ac = Ad.reshape(b, nc, chunk, h).transpose(0, 3, 1, 2)    # (b,h,nc,l)
-    Bc = jnp.repeat(Bm.reshape(b, nc, chunk, g, n), rep, axis=3).astype(jnp.float32)
-    Cc = jnp.repeat(Cm.reshape(b, nc, chunk, g, n), rep, axis=3).astype(jnp.float32)
-
-    A_cum = jnp.cumsum(Ac, axis=-1)                            # (b,h,nc,l)
-
-    # 1. intra-chunk
-    L = jnp.exp(_segsum(Ac))                                   # (b,h,nc,l,l)
-    Y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp", Cc, Bc, L, xc)
-
-    # 2. per-chunk final states
-    decay_states = jnp.exp(A_cum[..., -1:] - A_cum)            # (b,h,nc,l)
-    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn", Bc, decay_states, xc)
-
-    # 3. inter-chunk recurrence
-    if initial_state is None:
-        initial_state = jnp.zeros((b, h, p, n), jnp.float32)
-    states = jnp.concatenate([initial_state[:, None], states], axis=1)  # (b,nc+1,h,p,n)
-    chunk_sums = jnp.pad(A_cum[..., -1], ((0, 0), (0, 0), (1, 0)))      # (b,h,nc+1)
-    decay_chunk = jnp.exp(_segsum(chunk_sums))                 # (b,h,nc+1,nc+1)
-    new_states = jnp.einsum("bhzc,bchpn->bzhpn", decay_chunk, states)
-    prev_states, final_state = new_states[:, :-1], new_states[:, -1]
-
-    # 4. state contribution to outputs
-    state_decay = jnp.exp(A_cum)                               # (b,h,nc,l)
-    Y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp", Cc, prev_states, state_decay)
-
-    y = (Y_diag + Y_off).reshape(b, S, h, p)
-    return y, final_state
+# the einsum path's chunked SSD, under the layer's scope
+ssd_chunked = jax.named_scope(scopes.SSD_CORE)(_ssd_chunked.ssd_chunked)
 
 
 def ssd_recurrent_step(state, x_t, dt_t, A, B_t, C_t):
