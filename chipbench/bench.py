@@ -227,8 +227,8 @@ class Bench:
             peaks=peaks, chips=len(self.devices),
             tokens_per_step=self.mode.tokens_per_step, kernel_batch=kb,
             seq=t["seq"], root=self.root,
-            flops_per_token=registry.load_module(
-                "flops", "model", self.root).per_token(self.model, t["seq"]))
+            flops_per_token=registry.flops(self.model, self.root).per_token(
+                self.model, t["seq"]))
 
 
 def free(tree) -> None:

@@ -15,7 +15,8 @@ against its limit:
 - ``change_gap``: the same for the norm of each path's change from its
   initial value after the first steps, leaving out paths whose
   reference gradient is under a thousandth of the median path's (they
-  move under Adam by round-off alone).
+  move under Adam by round-off alone) unless the reference's ``update``
+  sets them (``reftrain``: a router's selection bias).
 """
 from __future__ import annotations
 
@@ -44,7 +45,8 @@ def readings(prog: Dict, ref: Dict) -> Dict[str, float]:
         raise ValueError("program and reference name different parameters")
     paths = sorted(ref["grad"])
     med = statistics.median(ref["grad"][p] for p in paths)
-    moved = [p for p in paths if ref["grad"][p] >= RULE_SHARE * med]
+    moved = [p for p in paths if ref["grad"][p] >= RULE_SHARE * med
+             or p in ref["updated"]]
     loss = max(abs(a - b) for a, b in zip(prog["losses"], ref["losses"]))
     gnorm = abs(prog["gnorm"] - ref["gnorm"]) / ref["gnorm"]
     return {"loss_gap": loss if math.isfinite(loss) else float("inf"),

@@ -1,9 +1,15 @@
 """Model FLOPs per trained token, from the configuration's shapes.
 
-6 x the matmul parameters (forward and backward; the LM head counted
-once, the embedding gather not at all), plus causal attention,
-6 L S H hd, plus the SSD recurrence, 12 H P N per layer.  Recomputed
-operations are not counted, and no kernel's block sizes enter.
+The convention that every count under ``flops/`` keeps (a family with a
+count of its own has ``flops/<family>.py``; this one counts the rest):
+
+- 6 x the matrix parameters that one token uses (forward and backward):
+  in an expert layer, the experts it is routed to, the shared experts
+  and the router; never capacity slots or padding;
+- the LM head counted once, the embedding gather not at all;
+- causal attention, 6 L S H hd (half of the S x S score and value
+  products), and the SSD recurrence, 12 H P N a layer;
+- recomputed operations not counted, and no kernel's block sizes.
 """
 from __future__ import annotations
 
@@ -22,8 +28,10 @@ def matmul_params(m: Dict) -> int:
         gn = m["ssm_ngroups"] * m["ssm_state"]
         per = d * (2 * dinner + 2 * gn + nh) + dinner * d
     else:
-        raise NotImplementedError(f"no FLOP count for family "
-                                  f"{m['family']!r}")
+        raise NotImplementedError(
+            f"flops/model.py counts the dense and ssm families, not "
+            f"{m['family']!r}: add flops/{m['family']}.py with its "
+            f"per_token(model, seq)")
     return L * per + d * V
 
 

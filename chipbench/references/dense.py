@@ -11,6 +11,11 @@ from chipbench.references._common import Ops, cross_entropy_sum, rmsnorm
 
 EMBED_PATH = "embed/tok"
 HEAD_PATHS = ("final_norm/scale", "embed/head")
+# a small model of this family, for the CPU test against the program
+CPU_MODEL = dict(name="t", family="dense", num_layers=2, d_model=64,
+                 num_heads=4, num_kv_heads=2, head_dim=16, d_ff=96,
+                 vocab_size=128, norm="rmsnorm", mlp="swiglu",
+                 rope_theta=1e6, max_seq_len=64, dtype="float32")
 
 
 def param_specs(m):
@@ -63,8 +68,9 @@ def attention(q, k, v, ops):
     return o.transpose(1, 2, 0, 3)
 
 
-def block(m, p, x, ops: Ops):
-    """One layer; ``p`` maps the layer's paths under ``blocks/``."""
+def block(m, p, x, ops: Ops, stack=None):
+    """One layer; ``p`` maps the layer's paths under ``blocks/``, the
+    one stack."""
     B, S, d = x.shape
     hd = m.get("head_dim") or d // m["num_heads"]
     h = rmsnorm(x, p["ln1/scale"])

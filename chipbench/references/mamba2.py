@@ -13,6 +13,12 @@ from chipbench.references._common import Ops, cross_entropy_sum, rmsnorm
 
 EMBED_PATH = "embed/tok"
 HEAD_PATHS = ("final_norm/scale", "embed/tok")
+# a small model of this family, for the CPU test against the program
+CPU_MODEL = dict(name="t", family="ssm", num_layers=2, d_model=64,
+                 num_heads=1, num_kv_heads=1, d_ff=0, vocab_size=128,
+                 ssm_state=16, ssm_expand=2, ssm_headdim=16, ssm_ngroups=1,
+                 ssm_conv_width=4, ssm_chunk=16, norm="rmsnorm",
+                 tie_embeddings=True, max_seq_len=64, dtype="float32")
 
 
 def _sizes(m):
@@ -76,7 +82,7 @@ def ssd(x, dt, A, Bm, Cm, ops: Ops):
     return jax.lax.map(jax.checkpoint(one_head), heads).transpose(1, 2, 0, 3)
 
 
-def block(m, p, x, ops: Ops):
+def block(m, p, x, ops: Ops, stack=None):
     B, S, d = x.shape
     dinner, nh, gn = _sizes(m)
     if m["ssm_ngroups"] != 1:

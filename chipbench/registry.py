@@ -78,6 +78,14 @@ def local_model(config: Dict) -> Dict:
     return m
 
 
+def flops(model: Dict, root: Optional[str] = None):
+    """The model FLOP count of ``model``'s family: ``flops/<family>.py``
+    where there is one, else ``flops/model.py``."""
+    family = model["family"]
+    return load_module("flops", family if family in names(
+        "flops", ".py", root) else "model", root)
+
+
 def peaks(device_kind: str, root: Optional[str] = None) -> Dict:
     """Published peaks of one chip of ``device_kind``.  A kind that is
     not in ``peaks.json`` is an error, never a default."""

@@ -1,6 +1,12 @@
 """Model and kernel work from the configurations' shapes, against
 numbers worked out by hand."""
-from chipbench import registry
+import os
+
+import jax
+import pytest
+
+import chipbench_testlib as lib
+from chipbench import bench, registry
 
 
 # mamba2-780m's widths at 24 of its 48 layers (the mamba2_l24 cell that
@@ -48,3 +54,45 @@ def test_kernel_work():
     assert nbytes == (2048 * 3072 * 2 + 2048 * 48 * 4 + 48 * 4
                       + 2 * 2048 * 128 * 2 + 2048 * 3072 * 4
                       + 48 * 64 * 128 * 4)
+
+
+def test_attention_work_at_two_head_widths():
+    fa = registry.load_module("flops", "flash_attention")
+    # latent attention's widths (Moonlight-16B-A3B: 16 heads, q.k at
+    # 128 + 64, p.v at 128), batch 2 x 1024
+    mla = dict(d_model=2048, num_heads=16, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128, dtype="bfloat16")
+    flops, nbytes = fa.work(mla, 2, 1024)
+    assert flops == 10_737_418_240     # 2 x 16 x 1024^2 x (192 + 128)
+    assert nbytes == 41_943_040        # 2 x 1024 x 16 x (384 + 256) x 2 B
+    # equal widths give what one head_dim gives
+    same = dict(mla, qk_nope_head_dim=64, qk_rope_head_dim=64)
+    plain = dict(d_model=2048, num_heads=16, head_dim=128,
+                 dtype="bfloat16")
+    assert fa.work(same, 2, 1024) == fa.work(plain, 2, 1024) == (
+        2 * 2 * 16 * 1024 * 1024 * 128, 4 * 2 * 1024 * 16 * 128 * 2)
+
+
+def test_other_families_are_told_to_add_a_count():
+    fl = registry.load_module("flops", "model")
+    with pytest.raises(NotImplementedError, match="flops/moe.py"):
+        fl.per_token(dict(MAMBA2_L24, family="moe"), 1024)
+
+
+def test_a_family_with_its_own_flops_file_gets_its_count(tmp_path):
+    root = lib.make_root(str(tmp_path))
+    with open(os.path.join(root, "flops", "dense.py"), "w",
+              encoding="utf-8") as f:
+        f.write("from chipbench import registry\n\n\n"
+                "def per_token(m, seq):\n"
+                "    return 2 * registry.load_module('flops', 'model')"
+                ".per_token(m, seq)\n")
+    peaks = registry.peaks("cpu", root)
+    default = registry.load_module("flops", "model")
+    for cell, factor in (("tiny_dense.t", 2), ("tiny_mamba.t", 1)):
+        b = bench.Bench(cell, jax.devices()[:1], root)
+        assert b.context(peaks).flops_per_token == factor * \
+            default.per_token(b.model, b.traffic["seq"])
+    assert registry.flops(dict(family="dense")) is default
+    assert registry.flops(dict(family="dense"),
+                          root).__name__.endswith("flops_dense")
